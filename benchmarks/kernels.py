@@ -1,7 +1,7 @@
 """Pallas kernels: correctness deltas vs the jnp oracles + oracle wall time.
 
-NOTE on timing: this container runs kernels in ``interpret=True`` (Python
-emulation) — wall-clock of the kernel itself is meaningless.  We therefore
+NOTE on timing: off the TPU the kernels run in the Pallas interpreter
+(Python emulation) — wall-clock of the kernel itself is meaningless.  We therefore
 report the XLA-compiled ORACLE's time as ``us_per_call`` (the baseline a TPU
 kernel must beat) and put the kernel-vs-oracle max error in ``derived``."""
 from __future__ import annotations

@@ -8,6 +8,24 @@ PerFedS² aggregation inner loop.
   stale_aggregate   Eq. (8) masked stale-gradient aggregation
 
 ``ops.py`` exposes jit'd wrappers; ``ref.py`` holds the pure-jnp oracles
-every kernel is tested against (interpret=True on this CPU container;
-set ``ops.INTERPRET = False`` on real TPUs).
+every kernel is tested against.  Interpret mode follows the platform
+(``interpret_mode``): the Pallas interpreter off the TPU, the compiled
+kernel on it.
 """
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Whether a Pallas call runs in interpret mode.
+
+    ``None`` (every normal caller) decides from the platform: interpreted
+    everywhere but the TPU, never on it.  An explicit bool is for compiling
+    a kernel for a described chip from a host whose backend is the CPU.
+    """
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 DEFAULT_BLOCK_S = 512
 NEG_INF = -1e30
 
@@ -66,7 +68,7 @@ def decode_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
                           pos: jax.Array, q_pos: jax.Array, *,
                           window: int = 0, scale: Optional[float] = None,
                           block_s: int = DEFAULT_BLOCK_S,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: Optional[bool] = None) -> jax.Array:
     """q [B, Hq, D]; k/v [B, Hkv, S, D]; pos [B, S]; q_pos [B] → [B, Hq, D]."""
     b, hq, d = q.shape
     hkv, s_len = k.shape[1], k.shape[2]
@@ -108,6 +110,6 @@ def decode_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q_pos.astype(jnp.int32), qf, kf, vf, pos.astype(jnp.int32))
     return out.reshape(b, hq, d)

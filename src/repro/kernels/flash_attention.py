@@ -1,4 +1,4 @@
-"""Blockwise flash attention (TPU Pallas target, validated interpret=True).
+"""Blockwise flash attention (TPU Pallas target; interpreted off the TPU).
 
 Online-softmax attention with explicit VMEM tiling via BlockSpec:
 
@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -77,11 +79,10 @@ def flash_attention_bhld(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          scale: Optional[float] = None,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """q [B, Hq, L, D], k/v [B, Hkv, L, D] → [B, Hq, L, D].
 
-    ``interpret=True`` runs the kernel body in Python on CPU (this container);
-    on a real TPU pass ``interpret=False``.
+    ``interpret`` defaults to the platform (``kernels.interpret_mode``).
     """
     b, hq, sl, d = q.shape
     hkv = k.shape[1]
@@ -120,6 +121,6 @@ def flash_attention_bhld(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),     # running max m
             pltpu.VMEM((block_q,), jnp.float32),     # running sum l
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return out[:, :, :sl, :]

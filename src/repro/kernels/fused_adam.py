@@ -1,4 +1,4 @@
-"""Fused Adam update kernel (TPU Pallas target, validated interpret=True).
+"""Fused Adam update kernel (TPU Pallas target; interpreted off the TPU).
 
 One pass over a flat parameter shard updates (p, m, v) together — three
 HBM-read + three HBM-write streams instead of the ~10 an unfused XLA graph
@@ -8,11 +8,14 @@ is reusable across steps without recompilation.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 BLOCK = 4096
 
@@ -36,7 +39,7 @@ def _adam_kernel(scal_ref, p_ref, m_ref, v_ref, g_ref,
 def fused_adam_flat(p: jax.Array, m: jax.Array, v: jax.Array, g: jax.Array,
                     *, lr, t, b1: float = 0.9, b2: float = 0.95,
                     eps: float = 1e-8, block: int = BLOCK,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Update one flat tensor.  p [N] (any float dtype), m/v [N] f32, g [N].
 
     Returns (new_p, new_m, new_v)."""
@@ -73,6 +76,6 @@ def fused_adam_flat(p: jax.Array, m: jax.Array, v: jax.Array, g: jax.Array,
             jax.ShapeDtypeStruct((n_pad,), jnp.float32),
         ],
         input_output_aliases={1: 0, 2: 1, 3: 2},
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(scal, p, m.astype(jnp.float32), v.astype(jnp.float32), g)
     return new_p[:n], new_m[:n], new_v[:n]

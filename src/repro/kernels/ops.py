@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Models call these through ``cfg.attn_impl == "pallas"`` etc.; tests compare
-each against the pure-jnp oracles in ``ref.py``.  ``interpret=True`` is the
-CPU-container default; flip to False on real TPUs.
+each against the pure-jnp oracles in ``ref.py``.  Every kernel picks interpret
+mode from the platform (``kernels.interpret_mode``), so the same call runs
+the Pallas interpreter on the CPU and the compiled kernel on a TPU.
 """
 from __future__ import annotations
 
@@ -21,7 +22,6 @@ from repro.kernels.stale_aggregate import stale_aggregate_flat
 # ``ref`` / ``stale_aggregate_flat`` / the tree aggregators below are
 # deliberate ops.* passthroughs (historical public entry points).
 __all__ = [
-    "INTERPRET",
     "flash_attention",
     "fused_adam_tree",
     "masked_aggregate_tree",
@@ -31,9 +31,6 @@ __all__ = [
     "stale_aggregate_tree",
 ]
 
-INTERPRET = True   # CPU container; set False on TPU
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0) -> jax.Array:
@@ -41,15 +38,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qt = jnp.moveaxis(q, 1, 2)
     kt = jnp.moveaxis(k, 1, 2)
     vt = jnp.moveaxis(v, 1, 2)
-    out = flash_attention_bhld(qt, kt, vt, causal=causal, window=window,
-                               interpret=INTERPRET)
+    out = flash_attention_bhld(qt, kt, vt, causal=causal, window=window)
     return jnp.moveaxis(out, 1, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-                c: jax.Array, chunk: int, *, interpret: bool = True
-                ) -> Tuple[jax.Array, jax.Array]:
+                c: jax.Array, chunk: int) -> Tuple[jax.Array, jax.Array]:
     """Pallas-backed drop-in for ``models.ssm.ssd_chunked``.
 
     x [B,L,H,P], dt [B,L,H], a [H], b/c [B,L,N] → (y [B,L,H,P], final_state).
@@ -65,8 +60,7 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 
     y_intra, states, chunk_decay, in_decay = ssd_chunk_pallas(
         xr.astype(jnp.float32), dtr.astype(jnp.float32),
-        a.astype(jnp.float32), br.astype(jnp.float32), cr.astype(jnp.float32),
-        interpret=interpret)
+        a.astype(jnp.float32), br.astype(jnp.float32), cr.astype(jnp.float32))
 
     # inter-chunk recurrence (linear in num_chunks — stays in jnp)
     def step(s_prev, inp):
@@ -84,15 +78,14 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     return y.astype(x.dtype), s_final.astype(x.dtype)
 
 
-def fused_adam_tree(params, m, v, grads, *, lr, t, b1=0.9, b2=0.95, eps=1e-8,
-                    interpret: bool = True):
+def fused_adam_tree(params, m, v, grads, *, lr, t, b1=0.9, b2=0.95, eps=1e-8):
     """Pytree fused-Adam: applies the flat kernel leaf-wise."""
     def upd(p, mi, vi, g):
         shape = p.shape
         np_, nm, nv = fused_adam_flat(
             p.reshape(-1), mi.reshape(-1), vi.reshape(-1),
             g.reshape(-1).astype(jnp.float32), lr=lr, t=t, b1=b1, b2=b2,
-            eps=eps, interpret=interpret)
+            eps=eps)
         return np_.reshape(shape), nm.reshape(shape), nv.reshape(shape)
 
     flat_p, treedef = jax.tree_util.tree_flatten(params)

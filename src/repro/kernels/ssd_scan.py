@@ -1,4 +1,4 @@
-"""Mamba-2 SSD chunk kernel (TPU Pallas target, validated interpret=True).
+"""Mamba-2 SSD chunk kernel (TPU Pallas target; interpreted off the TPU).
 
 The SSD algorithm splits the sequence into chunks; the quadratic chunk-local
 work (decay matrix + scores + per-chunk state summaries) is the compute hot
@@ -11,11 +11,13 @@ Per (batch, chunk) program, VMEM blocks:
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
@@ -50,7 +52,7 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
 
 
 def ssd_chunk_pallas(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-                     c: jax.Array, *, interpret: bool = True
+                     c: jax.Array, *, interpret: Optional[bool] = None
                      ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Chunk-local SSD terms.
 
@@ -84,7 +86,7 @@ def ssd_chunk_pallas(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
             jax.ShapeDtypeStruct((bs, nc, h), jnp.float32),
             jax.ShapeDtypeStruct((bs, nc, h, qlen), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, b, c, a)
 
 

@@ -1,6 +1,6 @@
 """Masked stale-gradient aggregation — Eq. (8) — behind ONE API.
 
-Flat Pallas kernel (TPU target, validated interpret=True):
+Flat Pallas kernel (TPU target; interpreted off the TPU):
 
     w ← w − (β/A) Σ_c π_c · buf_c
 
@@ -20,22 +20,27 @@ instead of hand-rolling ``tree_map`` reductions:
 
 Both flatten through a cached ``utils.tree.TreeFlattener`` (one concat
 buffer, treedef derived once per structure) and pick the backend:
-``"pallas"`` runs the kernel (interpret=True off-TPU), ``"jnp"`` a pure-JAX
+``"pallas"`` runs the kernel (interpreted off-TPU), ``"jnp"`` a pure-JAX
 matvec, ``"auto"`` uses Pallas only on a real TPU — interpret mode is a
 correctness oracle, not a fast path.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
 from repro.utils.tree import TreeFlattener
 
 BLOCK = 4096
+# Eq. (8) is an f32 weighted sum, as the kernel computes it; a TPU dot at
+# default precision would round the buffers to bf16 first
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _agg_kernel(scal_ref, mask_ref, p_ref, buf_ref, out_ref, *, n_cohorts: int):
@@ -49,7 +54,7 @@ def _agg_kernel(scal_ref, mask_ref, p_ref, buf_ref, out_ref, *, n_cohorts: int):
 
 def stale_aggregate_flat(params: jax.Array, buffers: jax.Array,
                          mask: jax.Array, *, beta: float,
-                         block: int = BLOCK, interpret: bool = True
+                         block: int = BLOCK, interpret: Optional[bool] = None
                          ) -> jax.Array:
     """params [N], buffers [C, N], mask [C] → updated params [N]."""
     n = params.shape[0]
@@ -72,7 +77,7 @@ def stale_aggregate_flat(params: jax.Array, buffers: jax.Array,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), params.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(scal, mask.astype(jnp.float32), params, buffers)
     return out[:n]
 
@@ -108,10 +113,10 @@ def stale_aggregate_update(p_flat: jax.Array, buf: jax.Array,
     backend = _resolve_backend(backend)
     mask = mask.astype(jnp.float32)
     if backend == "pallas":
-        return stale_aggregate_flat(p_flat, buf, mask, beta=beta,
-                                    interpret=jax.default_backend() != "tpu")
+        return stale_aggregate_flat(p_flat, buf, mask, beta=beta)
     a = jnp.maximum(mask.sum(), 1.0)
-    return p_flat - (jnp.asarray(beta, jnp.float32) / a) * (mask @ buf)
+    agg = jnp.dot(mask, buf, precision=_F32)
+    return p_flat - (jnp.asarray(beta, jnp.float32) / a) * agg
 
 
 def _stack_leafwise(payloads):
@@ -133,7 +138,8 @@ def masked_aggregate_tree(payloads, mask: jax.Array):
     mask = mask.astype(jnp.float32)
     a = jnp.maximum(mask.sum(), 1.0)
     return jax.tree.map(
-        lambda bl: jnp.tensordot(mask, bl.astype(jnp.float32), axes=1) / a,
+        lambda bl: jnp.tensordot(mask, bl.astype(jnp.float32), axes=1,
+                                 precision=_F32) / a,
         stacked)
 
 
@@ -166,7 +172,8 @@ def stale_aggregate_tree(params, payloads, mask: jax.Array, *, beta: float,
     scale = jnp.asarray(beta, jnp.float32) / a
 
     def upd(pl, bl):
-        agg = jnp.tensordot(mask, bl.astype(jnp.float32), axes=1)
+        agg = jnp.tensordot(mask, bl.astype(jnp.float32), axes=1,
+                            precision=_F32)
         return (pl.astype(jnp.float32) - scale * agg).astype(
             jnp.asarray(pl).dtype)
 

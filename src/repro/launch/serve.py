@@ -32,6 +32,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config

@@ -224,6 +224,7 @@ class LowerCase(NamedTuple):
     in_shardings: Tuple
     out_shardings: Any
     meta: Dict[str, Any]
+    init: Optional[Callable] = None   # train: rng → concrete args[0] (state)
 
 
 def build_case(model_cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
@@ -260,10 +261,10 @@ def build_case(model_cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
         if semi_sync_cohorts and semi_sync_cohorts > 1:
             step = semi_sync.make_semi_sync_step(model, exp, optimizer,
                                                  semi_sync_cohorts)
-            state_abs = jax.eval_shape(
-                functools.partial(semi_sync.init_state, model,
-                                  optimizer=optimizer,
-                                  n_cohorts=semi_sync_cohorts), rng)
+            init = functools.partial(semi_sync.init_state, model,
+                                     optimizer=optimizer,
+                                     n_cohorts=semi_sync_cohorts)
+            state_abs = jax.eval_shape(init, rng)
             batch_abs, batch_sh = train_batch_specs(
                 cfg, shape, mesh, triplet=True, n_cohorts=semi_sync_cohorts)
             mask_abs = _sds((semi_sync_cohorts,), jnp.float32)
@@ -278,9 +279,9 @@ def build_case(model_cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
         else:
             step = semi_sync.make_train_step(model, exp, optimizer,
                                              perfed_step=perfed_step)
-            state_abs = jax.eval_shape(
-                functools.partial(semi_sync.init_train_state, model,
-                                  optimizer=optimizer), rng)
+            init = functools.partial(semi_sync.init_train_state, model,
+                                     optimizer=optimizer)
+            state_abs = jax.eval_shape(init, rng)
             batch_abs, batch_sh = train_batch_specs(cfg, shape, mesh,
                                                     triplet=True)
             args = (state_abs, batch_abs, rng)
@@ -290,7 +291,7 @@ def build_case(model_cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
                               "grad_norm": NamedSharding(mesh, P())})
             name = f"{cfg.name}:{shape.name}:perfed" if perfed_step \
                 else f"{cfg.name}:{shape.name}:plain"
-        return LowerCase(name, step, args, in_sh, out_sh, meta)
+        return LowerCase(name, step, args, in_sh, out_sh, meta, init)
 
     if shape.kind == "prefill":
         batch_abs, batch_sh = train_batch_specs(cfg, shape, mesh,

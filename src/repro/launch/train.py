@@ -23,7 +23,6 @@ import time
 from dataclasses import replace
 
 
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description="PerFedS² training launcher")
     ap.add_argument("--mode", default="fl", choices=["fl", "scale"])
@@ -44,6 +43,9 @@ def main(argv=None):
                     help="write metrics.jsonl under this directory")
     ap.add_argument("overrides", nargs="*", help="dotted config overrides")
     args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.config import ExperimentConfig, apply_overrides, parse_cli_overrides
     from repro.configs import get_config

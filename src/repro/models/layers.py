@@ -12,7 +12,6 @@ Conventions
 """
 from __future__ import annotations
 
-import inspect as _inspect
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -22,18 +21,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro import sharding
 from repro.config import ModelConfig
-
-# jax ≥ 0.5 exposes jax.shard_map; 0.4.x has it under jax.experimental.
-# The replication-check kwarg was renamed check_rep → check_vma, not in
-# lockstep with the move, so probe the signature rather than the version.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
 
 Params = Dict[str, Any]
 
@@ -631,13 +618,13 @@ def moe_apply_ep(params: Params, x: jax.Array, cfg: ModelConfig
         out = jax.lax.psum(out, "model")
         return out.reshape(bb, ll, d)
 
-    out = _shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(x_spec, P(batch_axes, None, None), P(batch_axes, None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=x_spec,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(x, probs.astype(x.dtype), idx, w_gate, w_up, w_down)
 
     if e.num_shared_experts:

@@ -129,10 +129,9 @@ class _Span:
         self.child_s = 0.0
         self._ann = None
         if self.tr.profile:
-            ann = _annotation(self.name)
-            if ann is not None:
-                ann.__enter__()
-                self._ann = ann
+            import jax.profiler
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
         self.tr._stack.append(self)
         self.t0 = time.perf_counter()
         return self
@@ -151,14 +150,6 @@ class _Span:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         return False
-
-
-def _annotation(name: str) -> Optional[Any]:
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return None
 
 
 class Tracer:
@@ -239,23 +230,17 @@ class Tracer:
 def profile_trace(logdir: Optional[str]) -> Iterator[None]:
     """Bracket a run with ``jax.profiler.start_trace``/``stop_trace`` so
     it produces a TensorBoard-loadable profile under ``logdir``.  A falsy
-    ``logdir`` (or an unavailable profiler) degrades to a no-op."""
+    ``logdir`` is a no-op; a profiler that cannot start raises, so a run
+    that asked for a trace never ends without one."""
     if not logdir:
         yield
         return
-    try:
-        import jax.profiler as jp
-        jp.start_trace(logdir)
-    except Exception:
-        yield
-        return
+    import jax.profiler as jp
+    jp.start_trace(logdir)
     try:
         yield
     finally:
-        try:
-            jp.stop_trace()
-        except Exception:
-            pass
+        jp.stop_trace()
 
 
 # ---------------------------------------------------------------------------

@@ -143,6 +143,18 @@ def test_device_timing_off_never_blocks_or_books():
     assert tr.device_s == 0.0 and tr.device_phase_s == {}
 
 
+def test_profile_trace_raises_when_the_profiler_cannot_start(tmp_path):
+    """A run that asked for a trace never ends without one: a profiler
+    that cannot start (here: one is already running) raises."""
+    with obs_trace.profile_trace(str(tmp_path / "outer")):
+        with pytest.raises(RuntimeError, match="already been started"):
+            with obs_trace.profile_trace(str(tmp_path / "inner")):
+                pass
+    assert list((tmp_path / "outer").rglob("*.xplane.pb"))
+    with obs_trace.profile_trace(None):   # no logdir: a no-op
+        pass
+
+
 def test_staleness_histogram_clips_and_folds():
     h = staleness_histogram(np.array([0, 1, 1, 99, -5]), cap=4)
     assert h == [2, 2, 0, 0, 1] and sum(h) == 5
