@@ -33,8 +33,9 @@ def _grad(loss_fn: LossFn, params, batch, rng):
 
 def adapt(loss_fn: LossFn, params, batch, alpha: float, rng=None):
     """One inner SGD step: w' = w − α ∇f(w; D_in)  (the personalization step)."""
-    g = _grad(loss_fn, params, batch, rng)
-    return tree_axpy(-alpha, g, params)
+    with jax.named_scope("perfed.adapt"):
+        g = _grad(loss_fn, params, batch, rng)
+        return tree_axpy(-alpha, g, params)
 
 
 def hvp(loss_fn: LossFn, params, batch, vector, rng=None):
@@ -55,20 +56,23 @@ def perfed_grad(loss_fn: LossFn, params, batches: Dict[str, Any], alpha: float,
     if rng is not None:
         r1, r2, r3 = jax.random.split(rng, 3)
     w_adapted = adapt(loss_fn, params, batches["inner"], alpha, r1)
-    g_outer = _grad(loss_fn, w_adapted, batches["outer"], r2)
+    with jax.named_scope("perfed.outer"):
+        g_outer = _grad(loss_fn, w_adapted, batches["outer"], r2)
     if first_order:
         return g_outer
-    h = hvp(loss_fn, params, batches["hessian"], g_outer, r3)
-    return tree_axpy(-alpha, h, g_outer)
+    with jax.named_scope("perfed.hvp"):
+        h = hvp(loss_fn, params, batches["hessian"], g_outer, r3)
+        return tree_axpy(-alpha, h, g_outer)
 
 
 def perfed_loss(loss_fn: LossFn, params, batches: Dict[str, Any], alpha: float,
                 rng=None):
     """F_i(w) = f_i(w − α∇f_i(w; D_in); D_o) — the meta-objective value."""
-    r1, r2 = (jax.random.split(rng) if rng is not None else (None, None))
-    w_adapted = adapt(loss_fn, params, batches["inner"], alpha, r1)
-    out = loss_fn(w_adapted, batches["outer"], r2)
-    return out[0] if isinstance(out, tuple) else out
+    with jax.named_scope("perfed.loss"):
+        r1, r2 = (jax.random.split(rng) if rng is not None else (None, None))
+        w_adapted = adapt(loss_fn, params, batches["inner"], alpha, r1)
+        out = loss_fn(w_adapted, batches["outer"], r2)
+        return out[0] if isinstance(out, tuple) else out
 
 
 def perfed_grad_exact(loss_fn: LossFn, params, batch, alpha: float, rng=None):

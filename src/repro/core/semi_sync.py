@@ -101,19 +101,20 @@ def make_semi_sync_step(model, cfg: ExperimentConfig, optimizer: Optimizer,
         # -- 1) server update from arriving (possibly stale) gradients -------
         # via the unified aggregation API (same code path as the simulation
         # server and the engine's fused round / Pallas kernel)
-        if fused_eq8:
-            gnorm = jnp.zeros(())
-            new_params = stale_aggregate_tree(state.params, state.buffers,
-                                              mask, beta=fl.beta)
-            new_opt = state.opt_state
-        else:
-            agg = masked_aggregate_tree(state.buffers, mask)
-            if cfg.train.grad_clip:
-                agg, gnorm = clip_by_global_norm(agg, cfg.train.grad_clip)
-            else:
+        with jax.named_scope("semi_sync.eq8"):
+            if fused_eq8:
                 gnorm = jnp.zeros(())
-            new_params, new_opt = optimizer.update(agg, state.opt_state,
-                                                   state.params, fl.beta)
+                new_params = stale_aggregate_tree(state.params, state.buffers,
+                                                  mask, beta=fl.beta)
+                new_opt = state.opt_state
+            else:
+                agg = masked_aggregate_tree(state.buffers, mask)
+                if cfg.train.grad_clip:
+                    agg, gnorm = clip_by_global_norm(agg, cfg.train.grad_clip)
+                else:
+                    gnorm = jnp.zeros(())
+                new_params, new_opt = optimizer.update(agg, state.opt_state,
+                                                       state.params, fl.beta)
 
         # -- 2) refresh buffers: scheduled cohorts (+ over-stale ones) -------
         refresh = (mask > 0) | (state.staleness > fl.staleness_bound)
@@ -177,13 +178,14 @@ def make_train_step(model, cfg: ExperimentConfig, optimizer: Optimizer,
                 out = model.loss(p, batches["outer"], rng)
                 return out[0] if isinstance(out, tuple) else out
             loss, grads = jax.value_and_grad(scalar)(state.params)
-        if cfg.train.grad_clip:
-            grads, gnorm = clip_by_global_norm(grads, cfg.train.grad_clip)
-        else:
-            gnorm = jnp.zeros(())
-        lr = fl.beta if perfed_step else cfg.train.learning_rate
-        new_params, new_opt = optimizer.update(grads, state.opt_state,
-                                               state.params, lr)
+        with jax.named_scope("train.update"):
+            if cfg.train.grad_clip:
+                grads, gnorm = clip_by_global_norm(grads, cfg.train.grad_clip)
+            else:
+                gnorm = jnp.zeros(())
+            lr = fl.beta if perfed_step else cfg.train.learning_rate
+            new_params, new_opt = optimizer.update(grads, state.opt_state,
+                                                   state.params, lr)
         return TrainState(new_params, new_opt, state.step + 1), {
             "loss": loss, "grad_norm": gnorm}
 

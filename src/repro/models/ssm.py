@@ -168,26 +168,31 @@ class Mamba2LM:
         cfg = self.cfg
         d_inner, h, p, n = _dims(cfg)
         resid = x
-        xn = L.rmsnorm(pl["norm_attn"], x)
-        z, xbc, dt_raw = self._split_proj(xn @ pl["in_proj"])
-        # causal depthwise conv (width W): pad left
-        w = cfg.ssm.conv_width
-        pad = jnp.pad(xbc, ((0, 0), (w - 1, 0), (0, 0)))
-        conv = sum(pad[:, i:i + xbc.shape[1], :] * pl["conv_w"][i][None, None, :]
-                   for i in range(w)) + pl["conv_b"]
-        xbc = jax.nn.silu(conv)
-        xs = xbc[..., :d_inner].reshape(x.shape[0], x.shape[1], h, p)
-        b = xbc[..., d_inner:d_inner + n]
-        c = xbc[..., d_inner + n:]
-        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + pl["dt_bias"])
-        a = -jnp.exp(pl["A_log"])
-        y, _ = ssd_chunked(xs.astype(jnp.float32), dt, a,
-                           b.astype(jnp.float32), c.astype(jnp.float32),
-                           cfg.ssm.chunk_size)
-        y = y + pl["D_skip"][None, None, :, None] * xs.astype(jnp.float32)
-        y = y.reshape(x.shape[0], x.shape[1], d_inner).astype(x.dtype)
-        y = L.rmsnorm(pl["norm_gate"], y * jax.nn.silu(z))
-        return resid + y @ pl["out_proj"]
+        with jax.named_scope("ssm.in_proj"):
+            xn = L.rmsnorm(pl["norm_attn"], x)
+            z, xbc, dt_raw = self._split_proj(xn @ pl["in_proj"])
+        with jax.named_scope("ssm.conv"):
+            # causal depthwise conv (width W): pad left
+            w = cfg.ssm.conv_width
+            pad = jnp.pad(xbc, ((0, 0), (w - 1, 0), (0, 0)))
+            conv = sum(pad[:, i:i + xbc.shape[1], :]
+                       * pl["conv_w"][i][None, None, :]
+                       for i in range(w)) + pl["conv_b"]
+            xbc = jax.nn.silu(conv)
+            xs = xbc[..., :d_inner].reshape(x.shape[0], x.shape[1], h, p)
+            b = xbc[..., d_inner:d_inner + n]
+            c = xbc[..., d_inner + n:]
+        with jax.named_scope("ssm.ssd"):
+            dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + pl["dt_bias"])
+            a = -jnp.exp(pl["A_log"])
+            y, _ = ssd_chunked(xs.astype(jnp.float32), dt, a,
+                               b.astype(jnp.float32), c.astype(jnp.float32),
+                               cfg.ssm.chunk_size)
+            y = y + pl["D_skip"][None, None, :, None] * xs.astype(jnp.float32)
+        with jax.named_scope("ssm.out_proj"):
+            y = y.reshape(x.shape[0], x.shape[1], d_inner).astype(x.dtype)
+            y = L.rmsnorm(pl["norm_gate"], y * jax.nn.silu(z))
+            return resid + y @ pl["out_proj"]
 
     # --------------------------------------------------------- forward ----
     def forward(self, params: Params, tokens: jax.Array, **_kw):
@@ -202,13 +207,15 @@ class Mamba2LM:
             return f(pl, xc), 0
 
         x, _ = jax.lax.scan(body, x, params["layers"])
-        x = L.rmsnorm(params["final_norm"], x)
-        logits = L.unembed(params["embedding"], x)
+        with jax.named_scope("ssm.head"):
+            x = L.rmsnorm(params["final_norm"], x)
+            logits = L.unembed(params["embedding"], x)
         return logits, None, jnp.zeros((), jnp.float32)
 
     def loss(self, params, batch, rng=None):
         logits, _, _ = self.forward(params, batch["tokens"])
-        ce = L.cross_entropy(logits, batch["targets"], batch.get("mask"))
+        with jax.named_scope("ssm.head"):
+            ce = L.cross_entropy(logits, batch["targets"], batch.get("mask"))
         return ce, {"ce": ce}
 
     def predict(self, params, batch):
