@@ -8,6 +8,7 @@ from repro.core.perfed import (
     perfed_grad,
     perfed_grad_exact,
     perfed_loss,
+    perfed_value_and_grad,
 )
 from repro.core.scheduler import (
     estimate_A_K,
@@ -25,6 +26,7 @@ __all__ = [
     "perfed_grad",
     "perfed_grad_exact",
     "perfed_loss",
+    "perfed_value_and_grad",
     "relative_frequencies",
     "step_condition",
 ]

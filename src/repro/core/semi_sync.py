@@ -168,11 +168,9 @@ def make_train_step(model, cfg: ExperimentConfig, optimizer: Optimizer,
     def step_fn(state: TrainState, batches, rng
                 ) -> Tuple[TrainState, Dict[str, jax.Array]]:
         if perfed_step:
-            grads = perfed.perfed_grad(model.loss, state.params, batches,
-                                       fl.alpha, first_order=fl.first_order,
-                                       rng=rng)
-            loss = perfed.perfed_loss(model.loss, state.params, batches,
-                                      fl.alpha, rng=rng)
+            loss, grads = perfed.perfed_value_and_grad(
+                model.loss, state.params, batches, fl.alpha,
+                first_order=fl.first_order, rng=rng)
         else:
             def scalar(p):
                 out = model.loss(p, batches["outer"], rng)

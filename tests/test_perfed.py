@@ -93,6 +93,36 @@ def test_perfed_loss_value(setup):
     assert abs(got - want) < 1e-6
 
 
+@pytest.mark.parametrize("first_order", [False, True])
+def test_perfed_value_and_grad(setup, first_order):
+    """One Eq.-(7) pass gives F̃ and ∇̃F: the gradient is ``perfed_grad``'s
+    and the oracle's, the value is ``perfed_loss``'s on distinct batches."""
+    model, params, batch = setup
+    alpha = 0.05
+    keys = jax.random.split(jax.random.PRNGKey(7), 6)
+    distinct = {role: {"x": jax.random.normal(keys[2 * i], (32, 5)),
+                       "y": jax.random.normal(keys[2 * i + 1], (32, 3))}
+                for i, role in enumerate(("inner", "outer", "hessian"))}
+    value, grad = perfed.perfed_value_and_grad(
+        model.loss, params, distinct, alpha, first_order=first_order)
+    want = perfed.perfed_grad(model.loss, params, distinct, alpha,
+                              first_order=first_order)
+    assert float(tree_norm(tree_sub(grad, want))) == 0.0
+    loss = perfed.perfed_loss(model.loss, params, distinct, alpha)
+    assert abs(float(value) - float(loss)) < 1e-6
+
+    same = {"inner": batch, "outer": batch, "hessian": batch}
+    _, grad = perfed.perfed_value_and_grad(
+        model.loss, params, same, alpha, first_order=first_order)
+    if first_order:
+        w_ad = perfed.adapt(model.loss, params, batch, alpha)
+        oracle = jax.grad(lambda p: model.loss(p, batch)[0])(w_ad)
+    else:
+        oracle = perfed.perfed_grad_exact(model.loss, params, batch, alpha)
+    err = float(tree_norm(tree_sub(grad, oracle)) / tree_norm(oracle))
+    assert err < 1e-5, err
+
+
 def test_alpha_zero_recovers_plain_gradient(setup):
     model, params, batch = setup
     batches = {"inner": batch, "outer": batch, "hessian": batch}

@@ -5,9 +5,10 @@ import numpy as np
 import pytest
 
 from repro.config import ExperimentConfig, FLConfig, ModelConfig, TrainConfig
-from repro.core import semi_sync
+from repro.configs import get_config
+from repro.core import perfed, semi_sync
 from repro.models import build_model
-from repro.optim import make_optimizer
+from repro.optim import clip_by_global_norm, make_optimizer
 from repro.utils import tree_norm, tree_sub
 
 
@@ -103,3 +104,50 @@ def test_single_cohort_is_synchronous_perfedavg(setup, rng):
     p_state, _ = plain(p_state, flat_batches, rng)
     err = float(tree_norm(tree_sub(s_state.params, p_state.params)))
     assert err < 1e-5, err
+
+
+def test_perfed_step_takes_its_loss_from_the_outer_pass(rng):
+    """The reported meta-loss comes from the outer gradient's forward pass:
+    the step equals one that runs ``perfed_grad`` and ``perfed_loss`` apart,
+    bit for bit, and compiles to fewer FLOPs.  The layer scan keeps XLA from
+    merging the two forwards itself, so a second forward would show."""
+    cfg = ExperimentConfig(model=get_config("mamba2_370m").reduced(),
+                           fl=FLConfig(alpha=0.01, beta=0.05),
+                           train=TrainConfig(grad_clip=1.0))
+    model = build_model(cfg.model)
+    opt = make_optimizer("sgd")
+    fl = cfg.fl
+
+    def reference(state, batches, r):
+        grads = perfed.perfed_grad(model.loss, state.params, batches,
+                                   fl.alpha, rng=r)
+        loss = perfed.perfed_loss(model.loss, state.params, batches,
+                                  fl.alpha, rng=r)
+        grads, gnorm = clip_by_global_norm(grads, cfg.train.grad_clip)
+        params, opt_state = make_optimizer("sgd").update(
+            grads, state.opt_state, state.params, fl.beta)
+        return semi_sync.TrainState(params, opt_state, state.step + 1), {
+            "loss": loss, "grad_norm": gnorm}
+
+    def tokens(key):
+        toks = jax.random.randint(key, (2, 65), 0, cfg.model.vocab_size)
+        return {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
+
+    step = semi_sync.make_train_step(model, cfg, opt, perfed_step=True)
+    state = semi_sync.init_train_state(model, rng, opt)
+    k_in, k_out, k_h = jax.random.split(jax.random.PRNGKey(3), 3)
+    batches = {"inner": tokens(k_in), "outer": tokens(k_out),
+               "hessian": tokens(k_h)}
+
+    new = jax.jit(step).lower(state, batches, rng).compile()
+    ref = jax.jit(reference).lower(state, batches, rng).compile()
+    flops_new = new.cost_analysis()["flops"]
+    flops_ref = ref.cost_analysis()["flops"]
+    assert flops_new <= 0.96 * flops_ref, (flops_new, flops_ref)
+
+    s_new, m_new = new(state, batches, rng)
+    s_ref, m_ref = ref(state, batches, rng)
+    assert float(m_new["loss"]) == float(m_ref["loss"])
+    for a, b in zip(jax.tree.leaves(s_new.params),
+                    jax.tree.leaves(s_ref.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
