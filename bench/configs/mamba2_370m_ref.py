@@ -1,51 +1,26 @@
-"""Plain float32 reference of mamba2_370m under the PerFedS² step.
+"""Plain float32 reference of mamba2_370m: the model of the PerFedS² step.
 
 Written from the Mamba-2 paper (arXiv:2405.21060: the block of Fig. 6 and
-the minimal SSD algorithm of Listing 1) and the PerFedS² paper (Eq. 7, the
-Per-FedAvg meta-gradient with a forward-over-reverse Hessian-vector
-product, and the server's β-SGD step on the clipped gradient).  It imports
-nothing of the program.  Every matmul and einsum runs at
-``Precision.HIGHEST``; activations stay in float32.  After each step the
-parameters are stored in the dtype the configuration states for each leaf
-(bfloat16, and float32 for ``A_log``, ``dt_bias`` and ``D``), as the
-program's state stores them.
+the minimal SSD algorithm of Listing 1).  It imports nothing of the
+program.  ``bench/eq7_ref.py`` follows the step over ``loss``; the module
+gives what ``bench/harness.py`` asks of a reference: ``layout``, ``init``
+and ``loss``.
 
-``precision="default"`` runs every product at the TPU's default (one
-bfloat16 pass on float32 operands, forward and backward), with all else
-as above: it isolates the precision of the products, and is read only to
-find the cause of a gap (``bench/calibrate.py --diag``).
-
-The inner-adapted parameters w − α∇f(w; D_in) are parameters too, and are
-stored in the configuration's dtypes like those after a step.  Two
-readings look for the cause of a gap (``--diag``): ``adapt_f32=True``
-keeps the adapted parameters in float32, and ``emulate=True`` follows the
-program's own precision: the residual stream and every product's inputs
-rounded to bfloat16, and the products at the TPU's default precision.
-
-``lower=True`` gives the control: the same mathematics one precision
-lower than the configuration states — every stored parameter in float8
-e4m3 where the state is bfloat16 and in bfloat16 where it is float32, the
-inputs of every dense product rounded to float8 e4m3 (saturating) and
-those of the SSD einsums, which the configuration runs in float32, to
-bfloat16.  The backward pass sees the roundings straight through.
-
-Batches are consumed in blocks of rows and each layer is rematerialised,
-so the reference of a full-size step fits beside nothing else on one chip.
+The SSD einsums run in float32 in the configuration, so under the float8
+control (``q is round8``) their inputs are rounded to bfloat16, one
+precision lower, and those of the dense products to float8 e4m3.  Each
+layer is rematerialised.
 """
 from __future__ import annotations
 
 import functools
-import json
 import math
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-HIGHEST = jax.lax.Precision.HIGHEST
-PRECISION = {"highest": HIGHEST, "default": jax.lax.Precision.DEFAULT}
-F32_LEAVES = ("A_log", "dt_bias", "D_skip")
-LOWER = {"bfloat16": jnp.float8_e4m3fn, "float32": jnp.bfloat16}
+from bench.eq7_ref import HIGHEST, nest, round8, round16
 
 Tree = Dict[str, Any]
 
@@ -99,29 +74,6 @@ def layout(cfg: dict) -> Dict[str, Tuple[Tuple[int, ...], str]]:
     return out
 
 
-def flat(tree: Tree, prefix: str = "") -> Dict[str, Any]:
-    """Nested dict → {"a/b": leaf}."""
-    out = {}
-    for k, v in tree.items():
-        p = f"{prefix}{k}"
-        if isinstance(v, dict):
-            out.update(flat(v, p + "/"))
-        else:
-            out[p] = v
-    return out
-
-
-def nest(paths: Dict[str, Any]) -> Tree:
-    out: Tree = {}
-    for p, v in paths.items():
-        node = out
-        *head, last = p.split("/")
-        for k in head:
-            node = node.setdefault(k, {})
-        node[last] = v
-    return out
-
-
 def init(key, cfg: dict) -> Tree:
     """Random weights from ``key`` (the Mamba-2 initialisation), in the
     dtypes of ``layout``.  Jit it: it runs on the device in one call."""
@@ -161,23 +113,6 @@ def init(key, cfg: dict) -> Tree:
 # the model
 # ---------------------------------------------------------------------------
 
-FP8_MAX = 448.0      # largest finite float8 e4m3
-
-
-@jax.custom_jvp
-def _round8(x):
-    """Round to float8 e4m3, saturating (e4m3 has no infinity).  Tangents
-    and cotangents pass through unrounded (straight-through), as float8
-    training keeps its gradients wider."""
-    return jnp.clip(x, -FP8_MAX, FP8_MAX).astype(
-        jnp.float8_e4m3fn).astype(jnp.float32)
-
-
-@_round8.defjvp
-def _round8_jvp(primals, tangents):
-    return _round8(primals[0]), tangents[0]
-
-
 def _rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
@@ -189,10 +124,6 @@ def _segsum(x):
     xe = jnp.where(jnp.tril(jnp.ones((t, t), bool), -1), xe, 0.0)
     cs = jnp.cumsum(xe, axis=-2)
     return jnp.where(jnp.tril(jnp.ones((t, t), bool), 0), cs, -jnp.inf)
-
-
-def _round16(x):
-    return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
 def ssd(x, a, b, c, chunk: int, q: Callable, prec=HIGHEST):
@@ -239,7 +170,7 @@ def _layer(pl: Tree, x, cfg: dict, q: Callable, prec=HIGHEST):
     dt = jax.nn.softplus(dt_raw + pl["dt_bias"])
     a = -jnp.exp(pl["A_log"])
     # the SSD runs in float32 in the configuration: its control is bf16
-    q_ssd = _round16 if q is _round8 else q
+    q_ssd = round16 if q is round8 else q
     y = ssd(xs * dt[..., None], a * dt, bm, cm, cfg["chunk_size"], q_ssd,
             prec)
     y = y + pl["D_skip"][:, None] * xs
@@ -267,139 +198,3 @@ def loss(params: Tree, tokens, targets, cfg: dict, q: Callable = None,
     logz = jax.nn.logsumexp(logits, -1)
     gold = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
     return jnp.mean(logz - gold)
-
-
-# ---------------------------------------------------------------------------
-# Eq. 7 and the server step, over blocks of rows
-# ---------------------------------------------------------------------------
-
-def _blocks(batch: Tree, rows: int) -> Tree:
-    """[B, T] leaves → [B/rows, rows, T]."""
-    return jax.tree.map(lambda v: v.reshape((-1, rows) + v.shape[1:]), batch)
-
-
-def _add(a, b):
-    return jax.tree.map(jnp.add, a, b)
-
-
-class Reference:
-    """Jitted pieces of the reference step for one configuration."""
-
-    def __init__(self, cfg: dict, *, rows: int, lower: bool = False,
-                 precision: str = "highest", adapt_f32: bool = False,
-                 emulate: bool = False):
-        self.cfg, self.rows, self.lower = cfg, rows, lower
-        self.adapt_f32 = adapt_f32
-        q = _round8 if lower else (_round16 if emulate else None)
-        lossf = functools.partial(
-            loss, cfg=cfg, q=q,
-            prec=PRECISION["default" if emulate else precision],
-            act=_round16 if emulate else None)
-
-        def mean_grad(params, blocks):
-            def body(acc, blk):
-                val, g = jax.value_and_grad(lossf)(params, blk["tokens"],
-                                                   blk["targets"])
-                return (acc[0] + val, _add(acc[1], g)), None
-            zero = (jnp.zeros((), jnp.float32),
-                    jax.tree.map(jnp.zeros_like, params))
-            (tot, g), _ = jax.lax.scan(body, zero, blocks)
-            nb = blocks["tokens"].shape[0]
-            return tot / nb, jax.tree.map(lambda v: v / nb, g)
-
-        def mean_hvp(params, vec, blocks):
-            def body(acc, blk):
-                gfn = jax.grad(lambda p: lossf(p, blk["tokens"],
-                                               blk["targets"]))
-                return _add(acc, jax.jvp(gfn, (params,), (vec,))[1]), None
-            h, _ = jax.lax.scan(body, jax.tree.map(jnp.zeros_like, params),
-                                blocks)
-            nb = blocks["tokens"].shape[0]
-            return jax.tree.map(lambda v: v / nb, h)
-
-        self.mean_grad = jax.jit(mean_grad)
-        self.mean_hvp = jax.jit(mean_hvp)
-
-    def stored(self, params32: Tree, dtypes: Dict[str, Any]) -> Tree:
-        """Round float32 parameters to how the state stores them."""
-        fl = flat(params32)
-        out = {}
-        for p, v in fl.items():
-            dt = jnp.dtype(dtypes[p])
-            if self.lower:
-                dt = LOWER[dt.name]
-                if dt == jnp.float8_e4m3fn:
-                    v = jnp.clip(v, -FP8_MAX, FP8_MAX)
-            out[p] = v.astype(dt).astype(jnp.float32)
-        return nest(out)
-
-    def eq7(self, params: Tree, batches: Tree, alpha: float, dtypes=None):
-        """(meta-objective F(w), ∇̃F(w)) of Eq. 7 at float32 ``params``."""
-        blk = {r: _blocks(batches[r], self.rows)
-               for r in ("inner", "outer", "hessian")}
-        _, g_in = self.mean_grad(params, blk["inner"])
-        adapted = jax.tree.map(lambda w, g: w - alpha * g, params, g_in)
-        if not self.adapt_f32:
-            adapted = self.stored(adapted, dtypes)
-        del g_in
-        f_val, g_out = self.mean_grad(adapted, blk["outer"])
-        del adapted
-        h = self.mean_hvp(params, g_out, blk["hessian"])
-        return f_val, jax.tree.map(lambda g, hv: g - alpha * hv, g_out, h)
-
-
-@functools.lru_cache(maxsize=8)
-def _reference(cfg_json: str, rows: int, lower: bool, **kw) -> Reference:
-    """One set of jitted pieces per (configuration, block, precision)."""
-    return Reference(json.loads(cfg_json), rows=rows, lower=lower, **kw)
-
-
-def leaf_norms(tree: Tree) -> Dict[str, float]:
-    return {p: float(jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32)))))
-            for p, v in flat(tree).items()}
-
-
-def diff_norms(a: Tree, b: Tree) -> Dict[str, float]:
-    fa, fb = flat(a), flat(b)
-    return {p: float(jnp.sqrt(jnp.sum(jnp.square(
-        fa[p].astype(jnp.float32) - fb[p].astype(jnp.float32)))))
-        for p in fa}
-
-
-def train_readings(cfg: dict, params0: Tree, steps: List[Tree], *,
-                   rows: int, lower: bool = False, rows_used: int = 0,
-                   **kw) -> Dict[str, Any]:
-    """Follow the program's first ``len(steps)`` steps from ``params0``.
-
-    ``steps[i]`` is step i's Eq.-7 triplet.  ``rows_used`` > 0 keeps only
-    that many rows of each batch (the half-batch fault).  Returns each
-    step's meta-objective and pre-clip gradient norm, the per-leaf norm of
-    the first clipped gradient, and per-leaf norms of the stored change
-    after one step and after all of them.  ``kw`` goes to ``Reference``
-    (``precision``, ``adapt_f32``, ``emulate``).
-    """
-    tr = cfg["train"]
-    ref = _reference(json.dumps(cfg, sort_keys=True), rows, lower, **kw)
-    dtypes = {p: v.dtype for p, v in flat(params0).items()}
-    w0 = ref.stored(jax.tree.map(lambda v: v.astype(jnp.float32), params0),
-                    dtypes)
-    w = w0
-    out: Dict[str, Any] = {"loss": [], "grad_norm": []}
-    for i, batches in enumerate(steps):
-        if rows_used:
-            batches = jax.tree.map(lambda v: v[:rows_used], batches)
-        f_val, g = ref.eq7(w, batches, tr["alpha"], dtypes)
-        gn = jnp.sqrt(sum(jnp.sum(jnp.square(v)) for v in
-                          jax.tree.leaves(g)))
-        scale = jnp.minimum(1.0, tr["grad_clip"] / jnp.maximum(gn, 1e-12))
-        g = jax.tree.map(lambda v: v * scale, g)
-        if i == 0:
-            out["grad_leaf"] = leaf_norms(g)
-        w = ref.stored(jax.tree.map(lambda p, v: p - tr["beta"] * v, w, g),
-                       dtypes)
-        if i == 0:
-            out["change_first"] = diff_norms(w, w0)
-        out["loss"].append(float(f_val))
-        out["grad_norm"].append(float(gn))
-    out["change_last"] = diff_norms(w, w0)
-    return out

@@ -34,7 +34,7 @@ class Program:
         self.ctx = ctx
         cfg, wl = ctx.cell.config, ctx.cell.workload
         tr = cfg["train"]
-        model_cfg = tc.program_model_config(cfg)
+        model_cfg = ctx.cell.family.program_config(cfg)
         mesh = make_host_mesh()
         shape = ShapeConfig("bench", seq_len=wl["seq_len"],
                             global_batch=wl["batch"], kind="train")

@@ -1,4 +1,8 @@
-"""Model FLOPs of the PerFedS² step for the Mamba-2 family, from shapes.
+"""Family ``mamba2``: the program's Mamba-2 SSD stack (``models/ssm.py``).
+
+``program_config`` gives the program's ``ModelConfig`` at the sizes a
+configuration file states; ``forward_per_token`` the model FLOPs of one
+forward pass per token, from those sizes (``bench/harness.py``).
 
 Forward, per token (d model width, E = expand·d, H heads of P channels,
 N state, Q chunk, V vocabulary, L layers), counting the multiply-adds of
@@ -9,20 +13,6 @@ elementwise:
   C·Bᵀ within a chunk 2·Q·N, the intra-chunk output 2·Q·H·P, the chunk
   states 2·H·P·N and the inter-chunk output 2·H·P·N;
 * once: the output head 2·d·V, V padded as the head holds it.
-
-The Eq.-7 step, on T tokens per role (inner, outer, Hessian):
-
-* a gradient is a forward (F) and a backward pass (2F: each product
-  ``y = x·W`` costs ``dy·Wᵀ`` and ``xᵀ·dy``) — 3F;
-* the inner gradient on D_in and the outer gradient at the adapted point on
-  D_o are 3F each;
-* the Hessian-vector product on D_h is forward-over-reverse: the primal
-  gradient (3F) and its tangent, where every bilinear product ``a·b``
-  gets ``da·b + a·db`` (6F) — 9F.
-
-So one step is 15·T·F.  Not counted: recomputation under remat, the
-second inner adaptation that ``perfed_loss`` runs for the reported loss,
-and cohorts whose fresh gradient the step discards.
 """
 from __future__ import annotations
 
@@ -34,7 +24,26 @@ def vocab_rows(cfg: dict) -> int:
     return -(-cfg["vocab_size"] // m) * m
 
 
-def forward_per_token(cfg: dict) -> float:
+def program_config(cfg: dict):
+    """The program's ``ModelConfig`` at the sizes ``cfg`` states."""
+    import dataclasses
+
+    from repro.config import SSMConfig
+    from repro.configs import get_config
+
+    base = get_config(cfg["program_config"])
+    return dataclasses.replace(
+        base, num_layers=cfg["n_layer"], d_model=cfg["d_model"],
+        vocab_size=vocab_rows(cfg), tie_embeddings=cfg["tie_embeddings"],
+        dtype=cfg["dtype"],
+        ssm=SSMConfig(state_dim=cfg["d_state"], head_dim=cfg["headdim"],
+                      expand=cfg["expand"], chunk_size=cfg["chunk_size"],
+                      conv_width=cfg["d_conv"]))
+
+
+def forward_per_token(cfg: dict, seq_len: int) -> float:
+    """Per token, at any ``seq_len``: the SSD's work grows with the chunk,
+    not with the sequence."""
     d, depth, vocab = cfg["d_model"], cfg["n_layer"], vocab_rows(cfg)
     e = cfg["expand"] * d
     h = e // cfg["headdim"]
@@ -44,8 +53,3 @@ def forward_per_token(cfg: dict) -> float:
     layer = (2 * d * proj + 2 * e * d
              + 2 * q * n + 2 * q * h * p + 4 * h * p * n)
     return float(depth * layer + 2 * d * vocab)
-
-
-def eq7_step(cfg: dict, batch: int, seq_len: int) -> float:
-    """Model FLOPs of one cohort's Eq.-7 meta-gradient."""
-    return 15.0 * batch * seq_len * forward_per_token(cfg)

@@ -5,12 +5,37 @@ metrics; each is found here by its name alone:
 
 * ``workloads/<cell>.json``  — the cell: its config, driver kind, chips,
   traffic parameters and correctness limits;
-* ``configs/<config>.json``  — the sizes as run, with ``<config>_ref.py``
-  (the plain reference) beside it;
+* ``configs/<config>.json``  — the sizes as run, the ``family`` of the
+  program's models they belong to, and ``tiny``: the sizes a CPU test run
+  holds (``{"config": {...}, "workload": {...}}``, each laid over its file);
+* ``configs/<config>_ref.py`` — the plain reference of the model;
+* ``families/<family>.py``   — the family's side of the program;
 * ``drivers/<kind>.py``      — ``run(ctx) -> Outcome``;
 * ``metrics/<metric>.py``    — ``read(art) -> float | None``.
 
-A later cell or metric is a new file; nothing here changes for it.
+A family module gives
+
+* ``program_config(cfg) -> ModelConfig``: the program's configuration at
+  the sizes ``cfg`` states;
+* ``forward_per_token(cfg, seq_len) -> float``: the model FLOPs of one
+  forward pass per token of a ``seq_len``-token sequence, of this chip's
+  share of the model (matrix products and the like, two FLOPs a
+  multiply-add; nothing elementwise).
+
+A reference module imports nothing of the program and gives
+
+* ``layout(cfg) -> {path: (shape, dtype)}``: every parameter the step is
+  given, paths joined by ``/``;
+* ``init(key, cfg)``: random weights in those dtypes, as a nested dict,
+  made on the device in one jitted call;
+* ``loss(params, tokens, targets, cfg, q=None, prec=HIGHEST, act=None)``:
+  the mean next-token cross-entropy of float32 ``params``, every product
+  at precision ``prec``, with ``q`` applied to the inputs of every product
+  and ``act`` to the residual stream after the embedding and each layer
+  (``bench/eq7_ref.py`` passes its roundings).
+
+A later configuration, cell or metric is new files; nothing here changes
+for it.
 """
 from __future__ import annotations
 
@@ -60,6 +85,7 @@ class Cell:
     workload: dict            # workloads/<name>.json
     config: dict              # configs/<config>.json
     reference: ModuleType     # configs/<config>_ref.py
+    family: ModuleType        # families/<family>.py
     driver: ModuleType        # drivers/<kind>.py
     e2e: List[dict]           # BENCHMARK.json end_to_end entries it reports
     per_layer: List[dict]     # BENCHMARK.json per_layer entries it reports
@@ -81,12 +107,18 @@ def resolve(cell: str, bench: Path = BENCH, spec_: Optional[dict] = None
         raise ValueError(f"workloads/{cell}.json disagrees with "
                          "BENCHMARK.json on config or chips")
     cfg_name = entry["config"]
-    cfg = load_json(bench / "configs" / f"{cfg_name}.json")
+    cfg_file = bench / "configs" / f"{cfg_name}.json"
+    cfg = load_json(cfg_file)
+    fam_file = bench / "families" / f"{cfg.get('family')}.py"
+    if not fam_file.is_file():
+        raise FileNotFoundError(f"{cfg_file.name} names family "
+                                f"{cfg.get('family')!r}: no {fam_file}")
     ref = load_module(bench / "configs" / f"{cfg_name}_ref.py")
+    fam = load_module(fam_file)
     drv = load_module(bench / "drivers" / f"{wl['driver']}.py")
     e2e = [m for m in sp["end_to_end"] if _reports(m, cell)]
     per_layer = [m for m in sp["per_layer"] if _reports(m, cell)]
-    return Cell(cell, wl, cfg, ref, drv, e2e, per_layer)
+    return Cell(cell, wl, cfg, ref, fam, drv, e2e, per_layer)
 
 
 def metric_reader(name: str, bench: Path = BENCH) -> Callable:

@@ -1,6 +1,7 @@
 """``train_step_mfu``: the step's share of the chips' bf16 peak.
 
-Eq.-7 model FLOPs of the cohorts a step refreshes (``bench/flops``), times
+Eq.-7 model FLOPs of the cohorts a step refreshes
+(``train_common.eq7_step`` over the family's ``forward_per_token``), times
 the steps in the traced window, over window × chips × the published bf16
 peak of the device (``bench/peaks.json``).
 """

@@ -4,15 +4,18 @@ The program names its parts with ``jax.named_scope``; XLA writes the name
 stack into every HLO instruction's ``op_name`` metadata, and the profiler
 reports the same instructions on the device's ``XLA Ops`` line.  So the
 compiled step's text maps each operation of a trace to a path such as
-``jit(step_fn)/perfed.hvp/jvp(transpose(jvp()))/while/body/.../ssm.ssd/mul``.
+``jit(step_fn)/perfed.hvp/jvp(transpose(jvp()))/while/body/.../attn.core/mul``.
 
 * **Phase**: the first of ``PHASES`` on the path, else ``unscoped``.  Each
   operation falls in exactly one phase, so the phases sum to the busy time.
   A component may be wrapped in transforms (``transpose(jvp(perfed.hvp))``):
   the scope is matched as a whole component inside its wrappers.
 * **Parts**, counted in any phase: ``remat`` (JAX's ``rematted_computation``
-  on the path: work ``jax.checkpoint`` recomputes) and the model's ``ssm.*``
-  scopes.
+  on the path: work ``jax.checkpoint`` recomputes) and the model's own
+  scopes: the part is the first name on the path that holds a ``.`` and is
+  not a phase.  Python names hold no dot, so only ``jax.named_scope`` names
+  (``attn.core``, ``moe.experts``) qualify; of nested ones the outer
+  counts.
 
 An operation goes by its own instruction's metadata: a fusion by the
 fusion's, a loop's own time (its overhead) by the ``while`` instruction's.
@@ -46,7 +49,6 @@ PHASES = ("perfed.adapt", "perfed.outer", "perfed.hvp", "perfed.loss",
           "train.update", "semi_sync.eq8")
 UNSCOPED = "unscoped"
 REMAT = "rematted_computation"
-PART_PREFIX = "ssm."
 # control flow: the computations these call run as operations of their own
 CONTROL = ("while", "call", "conditional")
 # where run.py keeps the trace of a traced window until its readers are done
@@ -59,8 +61,8 @@ _WRAP_RE = re.compile(r"^[\w\-]+\((.*)\)$")
 
 def components(path: str) -> List[str]:
     """The scope names of an ``op_name`` path, transforms unwrapped:
-    ``jit(f)/perfed.hvp/jvp(transpose(jvp(ssm.head)))/mul`` gives
-    ``["f", "perfed.hvp", "ssm.head", "mul"]`` (an empty wrapper, ``jvp()``,
+    ``jit(f)/perfed.hvp/jvp(transpose(jvp(attn.core)))/mul`` gives
+    ``["f", "perfed.hvp", "attn.core", "mul"]`` (an empty wrapper, ``jvp()``,
     gives ``""``)."""
     parts, depth, cur = [], 0, []
     for ch in path:
@@ -84,7 +86,7 @@ def components(path: str) -> List[str]:
 @dataclass(frozen=True)
 class Scope:
     phase: str                # one of PHASES, or UNSCOPED
-    part: Optional[str]       # the first ssm.* scope, if any
+    part: Optional[str]       # the first model scope, if any
     remat: bool               # recomputed under jax.checkpoint
 
 
@@ -94,7 +96,7 @@ NONE = Scope(UNSCOPED, None, False)
 def classify(path: str) -> Scope:
     names = components(path)
     phase = next((n for n in names if n in PHASES), UNSCOPED)
-    part = next((n for n in names if n.startswith(PART_PREFIX)), None)
+    part = next((n for n in names if "." in n and n not in PHASES), None)
     return Scope(phase, part, REMAT in names)
 
 

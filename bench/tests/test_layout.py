@@ -33,6 +33,26 @@ def test_every_cell_resolves_to_its_config_driver_and_metrics(cell):
                           limits.values())
 
 
+@pytest.mark.parametrize("config", [c["name"] for c in SPEC["configs"]])
+def test_every_config_names_its_family_and_cpu_sizes(config):
+    """The family module builds, at the configuration's ``tiny`` sizes, the
+    program whose parameters are the leaves the reference defines."""
+    import jax
+
+    from bench import train_common as tc
+    from repro.models import build_model
+
+    cfg = harness.load_json(BENCH / "configs" / f"{config}.json")
+    fam = harness.load_module(BENCH / "families" / f"{cfg['family']}.py")
+    ref = harness.load_module(BENCH / "configs" / f"{config}_ref.py")
+    small = dict(cfg, **cfg["tiny"]["config"])
+    seq = cfg["tiny"]["workload"]["seq_len"]
+    assert fam.forward_per_token(small, seq) > 0
+    model = build_model(fam.program_config(small))
+    tc.check_layout(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                    ref.layout(small))
+
+
 def test_workload_files_are_all_cells():
     files = {p.stem for p in (BENCH / "workloads").glob("*.json")}
     assert files == {w["name"] for w in SPEC["workloads"]}

@@ -5,15 +5,15 @@ import functools
 import jax
 import pytest
 
-from bench import compare
+from bench import compare, eq7_ref
 from bench import train_common as tc
 from bench.tests import cells
 
-CELL = "mamba2_370m.perfed_step"
+CELLS = cells.train_cells()
 
 
-def run_with(tmp_path, monkeypatch, breaker=None):
-    cell = cells.tiny(CELL)
+def run_with(cell_name, tmp_path, monkeypatch, breaker=None):
+    cell = cells.tiny(cell_name)
     drv = cell.driver
     if breaker is not None:
         orig = drv.Program.__init__
@@ -25,8 +25,9 @@ def run_with(tmp_path, monkeypatch, breaker=None):
     return drv.run(cells.ctx(cell, tmp_path))
 
 
-def test_sound_run_is_correct(tmp_path, monkeypatch):
-    out = run_with(tmp_path, monkeypatch)
+@pytest.mark.parametrize("cell", CELLS)
+def test_sound_run_is_correct(cell, tmp_path, monkeypatch):
+    out = run_with(cell, tmp_path, monkeypatch)
     assert cells.correct(out), out.checks
     assert out.attempted >= 1 and out.compiles_in_window == 0
     assert out.e2e["train_tokens_per_s"] > 0
@@ -46,15 +47,17 @@ def half_batch(fn):
     return step
 
 
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("breaker", [unchanged, half_batch],
                          ids=["state_unchanged", "half_batch"])
-def test_broken_step_is_not_correct(tmp_path, monkeypatch, breaker):
-    out = run_with(tmp_path, monkeypatch, breaker)
+def test_broken_step_is_not_correct(cell, tmp_path, monkeypatch, breaker):
+    out = run_with(cell, tmp_path, monkeypatch, breaker)
     assert not cells.correct(out), out.checks
 
 
-def test_control_fails_the_limits():
-    cell = cells.tiny(CELL)
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_fails_the_limits(cell):
+    cell = cells.tiny(cell)
     ref, cfg, wl = cell.reference, cell.config, cell.workload
     params0 = jax.jit(functools.partial(ref.init, cfg=cfg))(
         jax.random.PRNGKey(5))
@@ -64,8 +67,9 @@ def test_control_fails_the_limits():
         for r in ("inner", "outer", "hessian")}
     steps = tc.draw_pool(shapes, cfg["vocab_size"], jax.random.PRNGKey(6),
                          wl["ref_steps"])
-    assert set(ref.flat(params0)) == set(lay)
-    sound = ref.train_readings(cfg, params0, steps, rows=1)
-    low = ref.train_readings(cfg, params0, steps, rows=1, lower=True)
+    assert set(eq7_ref.flat(params0)) == set(lay)
+    sound = eq7_ref.train_readings(ref, cfg, params0, steps, rows=1)
+    low = eq7_ref.train_readings(ref, cfg, params0, steps, rows=1,
+                                 lower=True)
     gaps = compare.train_gaps(low, sound)
     assert any(gaps[k] > v for k, v in wl["limits"].items()), gaps

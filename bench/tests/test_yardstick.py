@@ -8,9 +8,9 @@ import pytest
 
 from bench import compare, harness, hlo
 from bench import train_common as tc
-from bench.flops import ssm
 
 BENCH = Path(harness.__file__).resolve().parent
+mamba2 = harness.load_module(BENCH / "families" / "mamba2.py")
 
 
 def test_peaks_refuse_an_unknown_device_kind():
@@ -31,7 +31,7 @@ def test_ssm_forward_flops_match_the_compiled_dot_count(batch, seq):
     from repro.models import build_model
 
     cfg = tiny_mamba()
-    model = build_model(dataclasses.replace(tc.program_model_config(cfg),
+    model = build_model(dataclasses.replace(mamba2.program_config(cfg),
                                             remat=False))
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
@@ -39,13 +39,13 @@ def test_ssm_forward_flops_match_the_compiled_dot_count(batch, seq):
         params, tokens).compile()
     counted = hlo.analyze_hlo(compiled.as_text())["dot_flops_tc"]
     assert counted == pytest.approx(
-        batch * seq * ssm.forward_per_token(cfg), rel=1e-9)
+        batch * seq * mamba2.forward_per_token(cfg, seq), rel=1e-9)
 
 
 def test_eq7_step_is_fifteen_forward_passes_per_role_token():
     cfg = tiny_mamba()
-    assert ssm.eq7_step(cfg, 4, 2048) == 15 * 4 * 2048 * \
-        ssm.forward_per_token(cfg)
+    assert tc.eq7_step(mamba2, cfg, 4, 2048) == 15 * 4 * 2048 * \
+        mamba2.forward_per_token(cfg, 2048)
 
 
 def test_full_size_mamba2_forward_flops():
@@ -55,8 +55,9 @@ def test_full_size_mamba2_forward_flops():
     # 50,277 ids padded to a multiple of 16)
     per_layer = (2 * 1024 * 4384 + 2 * 2048 * 1024 + 2 * 256 * 128
                  + 2 * 256 * 32 * 64 + 4 * 32 * 64 * 128)
-    assert ssm.vocab_rows(cfg) == 50288
-    assert ssm.forward_per_token(cfg) == 48 * per_layer + 2 * 1024 * 50288
+    assert mamba2.vocab_rows(cfg) == 50288
+    assert mamba2.forward_per_token(cfg, 2048) == \
+        48 * per_layer + 2 * 1024 * 50288
 
 
 def test_major_gaps_leave_out_the_leaves_that_carry_no_update():

@@ -27,23 +27,6 @@ def keys_from_seed(seed: int, n: int = 2):
     return [int(x) for x in np.random.SeedSequence(seed).generate_state(n)]
 
 
-def program_model_config(cfg: dict):
-    """The program's ``ModelConfig`` at the sizes ``cfg`` states."""
-    from repro.config import SSMConfig
-    from repro.configs import get_config
-
-    from bench.flops.ssm import vocab_rows
-
-    base = get_config(cfg["program_config"])
-    return dataclasses.replace(
-        base, num_layers=cfg["n_layer"], d_model=cfg["d_model"],
-        vocab_size=vocab_rows(cfg), tie_embeddings=cfg["tie_embeddings"],
-        dtype=cfg["dtype"],
-        ssm=SSMConfig(state_dim=cfg["d_state"], head_dim=cfg["headdim"],
-                      expand=cfg["expand"], chunk_size=cfg["chunk_size"],
-                      conv_width=cfg["d_conv"]))
-
-
 def flat_leaves(tree) -> Dict[str, Any]:
     import jax
 
@@ -170,10 +153,13 @@ def compare_checks(ctx: harness.Ctx, prog: dict, ref: dict
 
 def reference_readings(ctx: harness.Ctx, params0, steps: List[Any],
                        **kw) -> dict:
-    """Run the cell's plain reference over the followed steps."""
-    wl = ctx.cell.workload
-    return ctx.cell.reference.train_readings(
-        ctx.cell.config, params0, steps, rows=wl["ref_block_rows"], **kw)
+    """Run the plain reference of the cell's model over the followed
+    steps."""
+    from bench import eq7_ref
+
+    return eq7_ref.train_readings(
+        ctx.cell.reference, ctx.cell.config, params0, steps,
+        rows=ctx.cell.workload["ref_block_rows"], **kw)
 
 
 def init_params(ref, cfg: dict, key, shardings=None):
@@ -183,10 +169,29 @@ def init_params(ref, cfg: dict, key, shardings=None):
                    out_shardings=shardings)(key)
 
 
+def eq7_step(family, cfg: dict, batch: int, seq_len: int) -> float:
+    """Model FLOPs of one cohort's Eq.-7 meta-gradient on ``batch`` rows of
+    ``seq_len`` tokens per role (inner, outer, Hessian), from one forward
+    pass per token (F, ``family.forward_per_token``):
+
+    * a gradient is a forward (F) and a backward pass (2F: each product
+      ``y = x·W`` costs ``dy·Wᵀ`` and ``xᵀ·dy``) — 3F;
+    * the inner gradient on D_in and the outer gradient at the adapted
+      point on D_o are 3F each;
+    * the Hessian-vector product on D_h is forward-over-reverse: the primal
+      gradient (3F) and its tangent, where every bilinear product ``a·b``
+      gets ``da·b + a·db`` (6F) — 9F.
+
+    So one step is 15·T·F on T = ``batch·seq_len`` tokens per role.  Not
+    counted: recomputation under remat, and cohorts whose fresh gradient
+    the step discards.
+    """
+    return 15.0 * batch * seq_len * family.forward_per_token(cfg, seq_len)
+
+
 def train_artifacts(ctx: harness.Ctx, timed: Timed, compiled) -> dict:
     """What the per-layer readers of a training cell read."""
     from bench import hlo
-    from bench.flops import ssm as ssm_flops
 
     cfg, wl = ctx.cell.config, ctx.cell.workload
     chips = int(wl["chips"])
@@ -201,8 +206,8 @@ def train_artifacts(ctx: harness.Ctx, timed: Timed, compiled) -> dict:
         "breakdown": tr.breakdown(devs[0], labels=BENCH_SPANS),
         "chips": chips,
         "peak_flops": ctx.peaks["bf16_flops"],
-        "model_flops_step": ssm_flops.eq7_step(cfg, wl["batch"],
-                                               wl["seq_len"]),
+        "model_flops_step": eq7_step(ctx.cell.family, cfg, wl["batch"],
+                                     wl["seq_len"]),
         "hlo_dot_flops_step": hlo.analyze_hlo(compiled.as_text())[
             "dot_flops_tc"],
         "hbm_gib": memory_gib(compiled),
